@@ -76,6 +76,7 @@ from repro.core.lifetime import LifetimeConfig
 from repro.core.range_shard import RangeShardedStore
 from repro.core.shard import ShardedStore
 from repro.core.store import ParallaxStore, StoreConfig
+from repro.kernels import use_compile_cache
 
 
 # --------------------------------------------------------------------- errors
@@ -1045,7 +1046,9 @@ def open(config: EngineConfig | None = None, **overrides) -> Engine:
 
     Field overrides may be passed as keywords, with or without a base config:
     ``open(partitioning="hash:4", execution="async")``.  Raises
-    :class:`ConfigError` on any invalid combination.
+    :class:`ConfigError` on any invalid combination.  Compiled device
+    programs persist in ``<checkout>/.jax_cache`` unless
+    ``JAX_COMPILATION_CACHE_DIR`` names another directory.
     """
     if config is None:
         config = EngineConfig(**overrides)
@@ -1055,6 +1058,7 @@ def open(config: EngineConfig | None = None, **overrides) -> Engine:
         raise ConfigError(
             f"open() takes an EngineConfig (or field overrides), got {type(config).__name__}"
         )
+    use_compile_cache()
     return Engine(config)
 
 

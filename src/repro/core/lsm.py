@@ -21,12 +21,23 @@ answer lets the store skip the level without touching the device (the
 ``bloom_skips`` counter in :class:`repro.core.store.StoreStats`).  Filters are
 in-memory and deterministic (crc32 double hashing), so they never change the
 store's visible state — only its read traffic.
+
+Each non-empty level also keeps its keys on the accelerator as a packed key
+column (:mod:`repro.kernels.merge_runs.ops`).  The compaction merge
+(:func:`merge_on_device`) orders two runs from their columns and hands the
+merged level its column, so only L0's keys are packed from host objects.
+:func:`merge_runs` is the plain reference the tests hold it to.
 """
 from __future__ import annotations
 
 import bisect
 import dataclasses
 import zlib
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels.merge_runs.ops import empty_column, merge_order, pack_keys
 
 from .logs import Pointer
 
@@ -97,8 +108,17 @@ class BloomFilter:
         return all(self._bits[pos >> 3] & (1 << (pos & 7)) for pos in self._positions(key))
 
 
+def pack_column(entries: list[IndexEntry]) -> jax.Array:
+    """The device key column of a sorted run of entries."""
+    return jnp.asarray(pack_keys([e.key for e in entries], [e.tombstone for e in entries]))
+
+
 class Level:
-    """A sorted run of IndexEntry (unique keys, ascending)."""
+    """A sorted run of IndexEntry (unique keys, ascending).
+
+    ``key_column`` is the run's packed key column on the device (``None``
+    while the level is empty).
+    """
 
     def __init__(self, index: int, bloom_bits_per_key: int = 0):
         self.index = index
@@ -109,13 +129,19 @@ class Level:
         self.transient_segments: list[int] = []  # medium-log segments attached here
         self.bloom_bits_per_key = bloom_bits_per_key
         self.bloom: BloomFilter | None = None
+        self.key_column: jax.Array | None = None
 
     def __len__(self) -> int:
         return len(self.entries)
 
-    def rebuild(self, entries: list[IndexEntry]) -> None:
+    def rebuild(self, entries: list[IndexEntry], key_column: jax.Array | None) -> None:
+        """Install ``entries`` with their device key column, the merge's
+        output; given no column, a non-empty run is repacked from its keys."""
         self.entries = entries
         self._keys = [e.key for e in entries]
+        if entries and key_column is None:
+            key_column = pack_column(entries)
+        self.key_column = key_column if entries else None
         self.index_bytes = sum(e.index_size() for e in entries)
         self.logical_bytes = sum(e.logical_size() for e in entries)
         if self.bloom_bits_per_key > 0 and entries:
@@ -131,7 +157,7 @@ class Level:
 
     def clear(self) -> list[int]:
         segs, self.transient_segments = self.transient_segments, []
-        self.rebuild([])
+        self.rebuild([], None)
         return segs
 
     def find(self, key: bytes) -> IndexEntry | None:
@@ -151,12 +177,34 @@ class Level:
             i += 1
 
 
+def merge_on_device(newer: list[IndexEntry], newer_column: jax.Array,
+                    older: list[IndexEntry], older_column: jax.Array | None, *,
+                    drop_tombstones: bool) -> tuple[list[IndexEntry], list[IndexEntry], jax.Array]:
+    """:func:`merge_runs` with the merge order computed on the device.
+
+    Returns ``(merged, superseded, merged_column)``: the first two exactly
+    as :func:`merge_runs` gives them, built by index from the device's
+    permutation and masks, and the merged run's key column.
+    """
+    order = merge_order(
+        newer_column, len(newer),
+        empty_column() if older_column is None else older_column, len(older),
+        drop_tombstones=drop_tombstones,
+    )
+    src = newer + older
+    live = ~(order.shadowed | order.dropped)
+    merged = [src[i] for i in order.perm[live].tolist()]
+    dead = [src[i] for i in order.perm[order.shadowed].tolist()]
+    dead += [src[i] for i in order.perm[order.dropped].tolist()]
+    return merged, dead, order.keys
+
+
 def merge_runs(newer: list[IndexEntry], older: list[IndexEntry], *, drop_tombstones: bool) -> tuple[list[IndexEntry], list[IndexEntry]]:
     """Merge two sorted runs; newer wins on key collision (it has higher LSN).
 
     Returns (merged, superseded) where ``superseded`` are the shadowed/dropped
     entries — the caller uses them to mark log slots dead (GC-region info,
-    paper §3.2) .
+    paper §3.2).  The plain reference for :func:`merge_on_device`.
     """
     merged: list[IndexEntry] = []
     dead: list[IndexEntry] = []

@@ -55,7 +55,7 @@ from typing import Iterable, Iterator
 from .io import BLOCK, SEGMENT, Device
 from .lifetime import CLASS_LONG, CLASS_SHORT, LifetimeConfig, LifetimeSketch, propose_cutoffs
 from .logs import Log, LogEntry, Pointer, TransientLog
-from .lsm import CAT_LARGE, CAT_MEDIUM, CAT_SMALL, IndexEntry, Level, merge_runs
+from .lsm import CAT_LARGE, CAT_MEDIUM, CAT_SMALL, IndexEntry, Level, merge_on_device, pack_column
 from .model import SizePolicy
 
 # virtual address regions so leaf probes of different levels hit different
@@ -277,7 +277,7 @@ class ParallaxStore:
         # covered by the L0->L1 compaction) — both value-log classes
         self.large_log.flush()
         self.short_log.flush()
-        self._merge_into(0, run, from_l0=True, src_segments=[])
+        self._merge_into(0, run, pack_column(run), from_l0=True, src_segments=[])
         self.compacted_lsn = max(self.compacted_lsn, max_lsn)
         # WAL reclaim: everything in the Small log is now durable in L1+
         self.small_log.flush()
@@ -300,16 +300,18 @@ class ParallaxStore:
             if lvl.index_bytes <= self._capacity(j):
                 j += 1
                 continue
-            run = lvl.entries
+            run, run_column = lvl.entries, lvl.key_column
             src_segs = lvl.clear()
             # reading the upper level for the merge (direct I/O, §3.4)
             self.device.sequential_read(sum(e.index_size() for e in run), self.device.segment_bytes, kind="compaction")
-            self._merge_into(j + 1, run, from_l0=False, src_segments=src_segs)
+            self._merge_into(j + 1, run, run_column, from_l0=False, src_segments=src_segs)
             self._write_redo_record()
             j += 1
 
-    def _merge_into(self, dst_idx: int, run: list[IndexEntry], *, from_l0: bool, src_segments: list[int]) -> None:
-        """Merge a sorted run (from L0 or level dst_idx-1) into levels[dst_idx]."""
+    def _merge_into(self, dst_idx: int, run: list[IndexEntry], run_column, *, from_l0: bool,
+                    src_segments: list[int]) -> None:
+        """Merge a sorted run (from L0 or level dst_idx-1, with its device key
+        column) into levels[dst_idx]."""
         cfg = self.config
         while len(self.levels) <= dst_idx:
             self.levels.append(Level(len(self.levels), cfg.bloom_bits_per_key))
@@ -319,8 +321,9 @@ class ParallaxStore:
         self.device.sequential_read(dst.index_bytes, self.device.segment_bytes, kind="compaction")
 
         is_last = dst_idx == len(self.levels) - 1
-        merged, dead = merge_runs(
-            run, dst.entries, drop_tombstones=is_last and not self.pin_tombstones
+        merged, dead, merged_column = merge_on_device(
+            run, run_column, dst.entries, dst.key_column,
+            drop_tombstones=is_last and not self.pin_tombstones,
         )
         self.stats.entries_merged += len(merged)
         for d in dead:
@@ -363,7 +366,9 @@ class ParallaxStore:
         else:
             for sid in consumed_segments:
                 self.medium_log.reclaim(sid)
-        dst.rebuild(out)
+        # relocation rewrites values and pointers, never keys: the merged
+        # column stays the level's key column
+        dst.rebuild(out, merged_column)
         dst.transient_segments = sorted(set(new_segments))
         # write the merged level (2 MB segment granularity direct I/O)
         self.device.sequential_write(dst.index_bytes, self.device.segment_bytes, kind="compaction")

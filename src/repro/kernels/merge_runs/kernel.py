@@ -1,17 +1,21 @@
 """Pallas TPU bitonic merge of two sorted runs (LSM compaction hot loop).
 
-Hardware adaptation (DESIGN.md §2.3): the paper's compaction merge is a
-pointer-walking two-finger merge — branchy, scalar, hostile to TPU vector
-units.  The TPU-native equivalent: concatenate run A (ascending) with run B
-*reversed* (descending) to form a bitonic sequence of length 2T, then run the
-log2(2T)-stage bitonic **merge network**.  Every stage is a reshape +
-element-wise min/max — no gathers, no data-dependent control flow, perfectly
-mapped to the VPU's (8, 128) lanes.  Payloads co-move via select on the key
-comparison.
+Hardware adaptation: the paper's compaction merge is a pointer-walking
+two-finger merge — branchy, scalar, hostile to TPU vector units.  The
+TPU-native equivalent: concatenate run A (ascending) with run B *reversed*
+(descending) to form a bitonic sequence of length 2T, then run the
+log2(2T)-stage bitonic **merge network**.  The wrapper reverses B and
+concatenates in XLA, so the kernel sees one (BG, 2T) block.  Every stage is a
+compare-exchange with the partner lane ``i XOR stride``: two lane rotations
+(``pltpu.roll``) fetch both neighbours, an iota-parity select picks the
+partner, and an element-wise min/max keeps the lower key in the lower lane.
+No gathers, no data-dependent control flow.  Payloads co-move via select on
+the key comparison.
 
 Grid: one program per row-group of tiles; each program holds its
-(BG, 2T) working set in VMEM.  T must be a power of two (the ops.py wrapper
-pads); keys int32/uint32/float32, payload any 32-bit dtype.
+(BG, 2T) working set in VMEM.  T must be a power of two; keys
+int32/float32, payload any 32-bit dtype.  This kernel merges single 32-bit
+keys and is not on the store's path (``ops.merge_order`` is).
 """
 from __future__ import annotations
 
@@ -20,41 +24,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _merge_stage(keys: jax.Array, vals: jax.Array, stride: int):
-    """One bitonic-merge compare-exchange stage at the given stride.
-
-    keys/vals: (BG, N).  Reshape to (BG, N/(2*stride), 2, stride) and
-    min/max along the 2-axis — the vectorized form of `compare with partner
-    idx XOR stride`.
-    """
-    bg, n = keys.shape
-    k4 = keys.reshape(bg, n // (2 * stride), 2, stride)
-    v4 = vals.reshape(bg, n // (2 * stride), 2, stride)
-    lo_k, hi_k = k4[:, :, 0], k4[:, :, 1]
-    lo_v, hi_v = v4[:, :, 0], v4[:, :, 1]
-    swap = lo_k > hi_k
-    nlo_k = jnp.where(swap, hi_k, lo_k)
-    nhi_k = jnp.where(swap, lo_k, hi_k)
-    nlo_v = jnp.where(swap, hi_v, lo_v)
-    nhi_v = jnp.where(swap, lo_v, hi_v)
-    keys = jnp.stack([nlo_k, nhi_k], axis=2).reshape(bg, n)
-    vals = jnp.stack([nlo_v, nhi_v], axis=2).reshape(bg, n)
-    return keys, vals
-
-
-def _merge_kernel(ak_ref, bk_ref, av_ref, bv_ref, ok_ref, ov_ref, *, tile: int):
-    ak = ak_ref[...]
-    av = av_ref[...]
-    # reverse B to form a bitonic sequence [A asc | B desc]
-    bk = jax.lax.rev(bk_ref[...], (1,))
-    bv = jax.lax.rev(bv_ref[...], (1,))
-    keys = jnp.concatenate([ak, bk], axis=1)
-    vals = jnp.concatenate([av, bv], axis=1)
-    stride = tile
+def _merge_kernel(k_ref, v_ref, ok_ref, ov_ref, *, width: int):
+    keys = k_ref[...]
+    vals = v_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    stride = width // 2
     while stride >= 1:
-        keys, vals = _merge_stage(keys, vals, stride)
+        # the rotation that brings lane i^stride to lane i, whichever way
+        # roll turns: compare the rotated lane ids against the partner ids
+        fwd = pltpu.roll(lane, stride, 1) == (lane ^ stride)
+        pk = jnp.where(fwd, pltpu.roll(keys, stride, 1), pltpu.roll(keys, width - stride, 1))
+        pv = jnp.where(fwd, pltpu.roll(vals, stride, 1), pltpu.roll(vals, width - stride, 1))
+        lower = (lane & stride) == 0
+        take = (lower & (keys > pk)) | (~lower & (pk > keys))
+        keys = jnp.where(take, pk, keys)
+        vals = jnp.where(take, pv, vals)
         stride //= 2
     ok_ref[...] = keys
     ov_ref[...] = vals
@@ -74,18 +61,18 @@ def merge_runs_pallas(
     assert t & (t - 1) == 0, f"tile width must be a power of two, got {t}"
     bg = min(block_rows, g)
     assert g % bg == 0, (g, bg)
-    grid = (g // bg,)
-    kernel = functools.partial(_merge_kernel, tile=t)
-    in_spec = pl.BlockSpec((bg, t), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((bg, 2 * t), lambda i: (i, 0))
+    # [A ascending | B descending] is bitonic
+    keys = jnp.concatenate([a_keys, jnp.flip(b_keys, 1)], axis=1)
+    vals = jnp.concatenate([a_vals, jnp.flip(b_vals, 1)], axis=1)
+    spec = pl.BlockSpec((bg, 2 * t), lambda i: (i, 0))
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[in_spec, in_spec, in_spec, in_spec],
-        out_specs=[out_spec, out_spec],
+        functools.partial(_merge_kernel, width=2 * t),
+        grid=(g // bg,),
+        in_specs=[spec, spec],
+        out_specs=[spec, spec],
         out_shape=[
             jax.ShapeDtypeStruct((g, 2 * t), a_keys.dtype),
             jax.ShapeDtypeStruct((g, 2 * t), a_vals.dtype),
         ],
         interpret=interpret,
-    )(a_keys, b_keys, a_vals, b_vals)
+    )(keys, vals)

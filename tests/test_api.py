@@ -297,3 +297,21 @@ def test_device_time_uses_config_overlap_policy():
 def test_execute_rejects_raw_stores():
     with pytest.raises(TypeError, match="drives an Engine"):
         api.execute(ParallaxStore(small_config()), iter([]))
+
+
+# -------------------------------------------------------------- compile cache
+@pytest.mark.parametrize("preset", [None, "elsewhere"])
+def test_open_keeps_compile_cache_in_checkout_unless_configured(preset, tmp_path):
+    import jax
+
+    from repro.kernels import CACHE_DIR
+
+    was = jax.config.jax_compilation_cache_dir
+    want = str(tmp_path / preset) if preset else str(CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / preset) if preset else None)
+    try:
+        api.open().close()
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert CACHE_DIR.name == ".jax_cache" and (CACHE_DIR.parent / "src" / "repro").is_dir()

@@ -8,7 +8,6 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.merge_runs.kernel import merge_runs_pallas
 from repro.kernels.merge_runs.ref import merge_runs_ref
-from repro.kernels.merge_runs.ops import merge_sorted_runs
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 from repro.kernels.ssd_scan.ref import ssd_scan_ref, ssd_reference_sequential
 
@@ -143,11 +142,3 @@ def test_merge_runs_with_duplicates():
     ok, _ = merge_runs_pallas(jnp.array(ak), jnp.array(bk), jnp.array(av), jnp.array(bv), interpret=True)
     assert np.array_equal(np.asarray(ok)[0], np.sort(np.concatenate([ak[0], bk[0]])))
 
-
-def test_merge_sorted_runs_full():
-    rng = np.random.default_rng(7)
-    a = np.sort(rng.integers(0, 1 << 28, 3000).astype(np.int32))
-    b = np.sort(rng.integers(0, 1 << 28, 1234).astype(np.int32))
-    mk, mv = merge_sorted_runs(jnp.array(a), jnp.array(b))
-    np.testing.assert_array_equal(np.asarray(mk), np.sort(np.concatenate([a, b])))
-    assert int((np.asarray(mv) == 0).sum()) == len(a)
